@@ -37,24 +37,12 @@ from repro.engine.registry import StageLibrary
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` when available, else the pre-0.5 experimental API.
-
-    The replication check is disabled on both APIs (``check_vma`` new /
-    ``check_rep`` legacy): stages contain custom-call primitives (eigvalsh)
-    without replication rules, and mesh axes the specs don't mention
-    (e.g. ``model``) would otherwise fail the check.
+    """``jax.shard_map`` with the replication check off: stages contain
+    custom-call primitives (eigvalsh) without replication rules, and mesh
+    axes the specs don't mention (e.g. ``model``) would otherwise fail it.
     """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:  # jax versions without the check_vma kwarg
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    return _legacy(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

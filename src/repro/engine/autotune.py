@@ -581,6 +581,9 @@ def main(argv=None) -> None:
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--k", type=int, default=4)
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     table = calibrate(smoke=args.smoke, batch=args.batch, k=args.k)
     path = table.save(Path(args.out))
     print(json.dumps(table.to_dict(), indent=2))

@@ -68,6 +68,7 @@ from collections import deque
 from concurrent.futures import Future, InvalidStateError
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from repro.engine import engine as engine_mod
@@ -368,10 +369,20 @@ class SubprocessReplica:
     same failover path the in-process driver exercises.  Each worker is
     pinned to limited XLA host threads (see ``fleet_worker``) so N workers
     scale on N cores instead of fighting over one.
+
+    Workers inherit the parent's platform (``JAX_PLATFORMS`` and all).  A
+    TPU belongs to one process, and a parent on the TPU backend already
+    holds it, so creating a replica there raises instead of starting
+    workers that would fail or hang waiting for the chip.
     """
 
     def __init__(self, rid: int, server_kwargs: Optional[dict] = None,
                  env: Optional[dict] = None, start_timeout_s: float = 120.0):
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "SubprocessReplica cannot start: this process holds the TPU "
+                "and a chip serves one process at a time; use "
+                "replica_mode='inprocess' on a chip host")
         self.rid = rid
         self._lock = threading.Lock()
         self._outstanding: "dict[int, tuple[Future, float]]" = {}
@@ -382,7 +393,6 @@ class SubprocessReplica:
             os.path.abspath(__file__))))
         worker_env["PYTHONPATH"] = src_root + os.pathsep + \
             worker_env.get("PYTHONPATH", "")
-        worker_env.setdefault("JAX_PLATFORMS", "cpu")
         if env:
             worker_env.update(env)
         self._proc = subprocess.Popen(

@@ -23,10 +23,12 @@ up into the parent's dispatch path, and a chaos ``slow`` delays each
 forward like an overloaded process would.  Results are written from the
 server's retire-thread callbacks under one write lock.
 
-The worker pins XLA's CPU backend to a small thread pool unless the
-parent overrides it: a fleet of N workers on an N-core host should scale
-by *process* parallelism, not have each worker's eigensolver fight over
-every core.
+The worker runs on the platform it inherits from the parent's
+environment (the parent refuses to spawn workers while it holds a TPU).
+It pins XLA's CPU backend to a small thread pool unless the parent
+overrides it: a fleet of N workers on an N-core host should scale by
+*process* parallelism, not have each worker's eigensolver fight over every
+core.
 """
 
 from __future__ import annotations
@@ -40,7 +42,6 @@ import time
 
 def _configure_host() -> None:
     # Must run before jax import: XLA reads these at backend init.
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "intra_op_parallelism_threads" not in flags:
         os.environ["XLA_FLAGS"] = (

@@ -241,6 +241,11 @@ class SolverPlan:
             raise ValueError(f"unknown spectrum {self.spectrum!r}")
         if self.precision not in (None, "float32", "float64"):
             raise ValueError(f"unknown precision {self.precision!r}")
+        if self.backend == "pallas" and self.precision == "float64":
+            raise ValueError(
+                "precision='float64' cannot run on the pallas backend: its "
+                "TPU kernels take float32 only; plan backend='jnp' or "
+                "'reference' for float64")
         if self.backend == "sharded":
             if self.mesh is None:
                 raise ValueError("backend='sharded' requires a mesh")

@@ -121,6 +121,9 @@ class SpectralSession:
         self.fast_updates = 0
         self.full_resolves = 0
         self.resolves_by_cause: dict = {}
+        # Windows rebuilt by host LAPACK instead of a device solve (a
+        # re-solve that failed verification, or the server's degrade rung).
+        self.host_reseeds = 0
 
     def result(self):
         """The current top-k window as a ``TopkResult``."""
@@ -135,6 +138,7 @@ class SpectralSession:
             "fast_updates": self.fast_updates,
             "full_resolves": self.full_resolves,
             "resolves_by_cause": dict(self.resolves_by_cause),
+            "host_reseeds": self.host_reseeds,
             "drift": self.drift,
             "updates_since_resolve": self.updates_since_resolve,
         }
@@ -223,6 +227,7 @@ def host_reseed(session, a_new, cause: str = "degrade") -> None:
             f"session host re-solve (cause={cause!r}) failed residual "
             "verification; the session matrix is pathological")
     _commit_resolve(session, a_new, res, cause)
+    session.host_reseeds += 1
 
 
 def _full_resolve(engine, session, a_new, cause: str) -> None:

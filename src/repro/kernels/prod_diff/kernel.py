@@ -23,7 +23,13 @@ Design (TPU-native re-think of the paper's "batched products"):
 * layout: ``i`` on sublanes, ``j`` on lanes, ``k`` swept inside the tile in
   sublane-sized chunks (a ``fori_loop`` of rank-3 ``(bi, 8, bj)`` VPU ops —
   8 mu rows per step instead of one, working set = one ``(bk, bj)`` mu tile
-  + one chunk + one ``(bi, bj)`` accumulator);
+  + one chunk + one ``(bi, bj)`` accumulator).  Each chunk is read straight
+  from the VMEM ref at a sublane-aligned offset
+  (``pl.ds(pl.multiple_of(c * 8, 8), 8)``): Mosaic does not lower a dynamic
+  slice of an already-loaded value.  VMEM: at the static ``128^3``,
+  ``bb = 1`` tiles, lam (its ``(bi, 1)`` column pads to 128 lanes), mu,
+  mask and out are one 64 KiB f32 tile each, about 0.5 MiB double-buffered
+  — far under v5e's 16 MiB scoped limit;
 * ``mu`` is passed transposed ``(K, J)`` so the lane dimension of every load
   matches the lane dimension of the output tile (no in-kernel transposes).
 
@@ -58,13 +64,12 @@ def _logabs_sum_batched_kernel(
         out_ref[...] = jnp.zeros_like(out_ref)
 
     lam = lam_ref[...]  # (bb, bi, 1)
-    mut = mut_ref[...]  # (bb, bk, bj)
-    mask = mask_ref[...]  # (bk, bj), shared across the batch axis
     floor = floor_ref[...]  # (bb, 1, 1) per-matrix gap clamp
 
     def body(c, acc):
-        mu_c = jax.lax.dynamic_slice_in_dim(mut, c * K_CHUNK, K_CHUNK, axis=1)
-        m_c = jax.lax.dynamic_slice_in_dim(mask, c * K_CHUNK, K_CHUNK, axis=0)
+        rows = pl.ds(pl.multiple_of(c * K_CHUNK, K_CHUNK), K_CHUNK)
+        mu_c = mut_ref[:, rows, :]  # (bb, K_CHUNK, bj)
+        m_c = mask_ref[rows, :]  # (K_CHUNK, bj), shared across the batch
         # (bb, bi, K_CHUNK, bj): bb matrices advance in one VPU op.
         ad = jnp.abs(lam[:, :, :, None] - mu_c[:, None, :, :])
         ad = jnp.where(
@@ -139,13 +144,12 @@ def _logabs_sum_batched_masked_kernel(
         out_ref[...] = jnp.zeros_like(out_ref)
 
     lam = lam_ref[...]  # (bb, bi, 1)
-    mut = mut_ref[...]  # (bb, bk, bj)
-    mask = mask_ref[...]  # (bb, bk, bj) — per-matrix validity
     floor = floor_ref[...]  # (bb, 1, 1)
 
     def body(c, acc):
-        mu_c = jax.lax.dynamic_slice_in_dim(mut, c * K_CHUNK, K_CHUNK, axis=1)
-        m_c = jax.lax.dynamic_slice_in_dim(mask, c * K_CHUNK, K_CHUNK, axis=1)
+        rows = pl.ds(pl.multiple_of(c * K_CHUNK, K_CHUNK), K_CHUNK)
+        mu_c = mut_ref[:, rows, :]  # (bb, K_CHUNK, bj)
+        m_c = mask_ref[:, rows, :]  # (bb, K_CHUNK, bj) — per-matrix validity
         ad = jnp.abs(lam[:, :, :, None] - mu_c[:, None, :, :])
         ad = jnp.where(
             m_c[:, None, :, :] > 0,
@@ -215,13 +219,11 @@ def _logabs_sum_kernel(lam_ref, mut_ref, mask_ref, floor_ref, out_ref, *, block_
         out_ref[...] = jnp.zeros_like(out_ref)
 
     lam = lam_ref[...]  # (bi, 1) sublane vector
-    mut = mut_ref[...]  # (bk, bj)
-    mask = mask_ref[...]  # (bk, bj)
     floor = floor_ref[0, 0]
 
     def body(kk, acc):
-        mu_row = jax.lax.dynamic_slice_in_dim(mut, kk, 1, axis=0)  # (1, bj)
-        m_row = jax.lax.dynamic_slice_in_dim(mask, kk, 1, axis=0)  # (1, bj)
+        mu_row = mut_ref[pl.ds(kk, 1), :]  # (1, bj)
+        m_row = mask_ref[pl.ds(kk, 1), :]  # (1, bj)
         ad = jnp.abs(lam - mu_row)  # (bi, bj)
         ad = jnp.where(m_row > 0, jnp.maximum(ad, floor), 1.0)
         return acc + jnp.log(ad)

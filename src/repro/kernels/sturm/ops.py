@@ -21,6 +21,16 @@ def _default_iters(dtype) -> int:
     return 64 if dtype == jnp.float64 else 32
 
 
+def _shifted_band(e: jax.Array, d_p: jax.Array) -> jax.Array:
+    """The kernels' sub-diagonal layout: ``e`` ``(B, n-1)`` placed at
+    columns ``1..n-1`` of a zero array shaped like the padded band."""
+    n = e.shape[1] + 1
+    e_p = jnp.zeros_like(d_p)
+    if n > 1:
+        e_p = e_p.at[:e.shape[0], 1:n].set(e)
+    return e_p
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n_iter", "block_b", "block_m", "interpret", "window"),
@@ -83,15 +93,15 @@ def sturm_eigenvalues(
     pivmin = jnp.maximum(eps * eps * scale * scale, tiny)
     bounds = jnp.stack([lo, hi, pivmin, jnp.full((b_n,), n, dtype)], axis=1)
 
-    # Clamp blocks to the padded problem shape: a 128-lane tile on an n=8
-    # problem must shrink to 8, not pad the band 16x (align 8 keeps lanes
-    # aligned; the batch axis clamps unaligned — padded rows are pure waste).
-    # The eigenvalue-index (target) axis and the band axis coincide only for
-    # full-spectrum runs: a window tiles k target lanes over the full band.
+    # Clamp blocks to the padded problem shape: a 128-lane target tile on an
+    # n=8 problem must shrink to 8 (align 8 keeps lanes aligned; the batch
+    # axis clamps unaligned — padded rows are pure waste).  The band pads
+    # separately, to the kernel's chunk width; a window tiles k target lanes
+    # over the full band.
     block_m = blocks.clamp_block(block_m, m_targets)
     block_b = blocks.clamp_block(block_b, b_n, align=1)
     pad_m = (-m_targets) % block_m
-    pad_n = (-n) % block_m if window is None else (-n) % 8
+    pad_n = _kernel.band_width(n) - n
     pad_b = (-b_n) % block_b
     # Padded diagonal entries sit above hi (decoupled via zero e), so padded
     # eigenvalue indices converge onto hi and are sliced off below.
@@ -102,9 +112,7 @@ def sturm_eigenvalues(
         d_p = jnp.where(
             col >= n, jnp.pad(big, ((0, pad_b), (0, 0)), constant_values=1.0), d_p
         )
-    e_p = jnp.zeros_like(d_p)
-    if n > 1:
-        e_p = e_p.at[:b_n, : n - 1].set(e)
+    e_p = _shifted_band(e, d_p)
     bounds_p = jnp.pad(bounds, ((0, pad_b), (0, 0)), constant_values=1.0)
 
     out = _kernel.sturm_padded(
@@ -209,7 +217,7 @@ def sturm_eigenvalues_segmented(
     block_b = blocks.clamp_block(block_b, b_n, align=1)
     pad_m = (-m_total) % block_m
     pad_b = (-b_n) % block_b
-    pad_n = (-n) % 8
+    pad_n = _kernel.band_width(n) - n
 
     def pad_lane(x, value):
         """Broadcast (B, S[, k]) to lanes (B, S*k) and pad to blocks."""
@@ -225,9 +233,7 @@ def sturm_eigenvalues_segmented(
     targ_l = pad_lane(targ, 0)
 
     d_p = jnp.pad(d, ((0, pad_b), (0, pad_n)), constant_values=1.0)
-    e_p = jnp.zeros_like(d_p)
-    if n > 1:
-        e_p = e_p.at[:b_n, : n - 1].set(e)
+    e_p = _shifted_band(e, d_p)
 
     out = _kernel.sturm_segmented_padded(
         d_p, e_p, lo_l, hi_l, piv_l, start_l, end_l, targ_l,
@@ -314,7 +320,7 @@ def sturm_eigenvalues_bracketed(
     block_b = blocks.clamp_block(block_b, b_n, align=1)
     pad_m = (-k) % block_m
     pad_b = (-b_n) % block_b
-    pad_n = (-n) % 8
+    pad_n = _kernel.band_width(n) - n
 
     def pad_lane(x, value):
         return jnp.pad(x, ((0, pad_b), (0, pad_m)), constant_values=value)
@@ -327,9 +333,7 @@ def sturm_eigenvalues_bracketed(
     targ_l = pad_lane(targ, 0)
 
     d_p = jnp.pad(d, ((0, pad_b), (0, pad_n)), constant_values=1.0)
-    e_p = jnp.zeros_like(d_p)
-    if n > 1:
-        e_p = e_p.at[:b_n, : n - 1].set(e)
+    e_p = _shifted_band(e, d_p)
 
     out = _kernel.sturm_segmented_padded(
         d_p, e_p, lo_l, hi_l, piv_l, start_l, end_l, targ_l,

@@ -57,6 +57,7 @@ import numpy as np
 from repro.configs.registry import ARCHS, get_config, reduced_config
 from repro.launch import mesh as mesh_lib
 from repro.launch.train import parse_mesh
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.models.lm import LanguageModel
 from repro.train import build_programs
 from repro.train.steps import cast_tree
@@ -209,6 +210,7 @@ def serve_eei(args):
                              sorted(stats["chaos_injected"].items()))
         log.info("chaos injected: %s | requests_failed=%d",
                  injected or "none", stats["requests_failed"])
+    _require_clean(futures, chaos_armed=chaos is not None)
     # A zero-request stream (--requests 0: config smoke, drained replay)
     # has no futures — the rollups above already guard division by zero /
     # empty percentiles; returning None instead of futures[-1] keeps the
@@ -278,7 +280,21 @@ def _serve_eei_fleet(args, stream, gap_s, rng):
                              if count)
         log.info("chaos injected: %s | requests_failed=%d",
                  injected or "none", stats["requests_failed"])
+    _require_clean(futures, chaos_armed=chaos is not None)
     return futures[-1].result() if futures else None
+
+
+def _require_clean(futures, chaos_armed: bool) -> None:
+    """Exit non-zero when a request resolved degraded or failed and no
+    chaos was armed to explain it: a degraded result took the host
+    fallback chain, so the run did not show the device path serving."""
+    failed = sum(f.exception() is not None for f in futures)
+    degraded = sum(bool(getattr(f.result(), "degraded", False))
+                   for f in futures if f.exception() is None)
+    if (failed or degraded) and not chaos_armed:
+        raise SystemExit(
+            f"eei serve: {degraded} of {len(futures)} requests resolved "
+            f"degraded and {failed} failed without --chaos")
 
 
 def main(argv=None):
@@ -358,6 +374,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
 
     if args.eei:
         return serve_eei(args)

@@ -28,6 +28,7 @@ from repro.launch import mesh as mesh_lib
 from repro.models.lm import LanguageModel
 from repro.optim import AdamW, EigenPre
 from repro.runtime import Supervisor, SupervisorConfig, StragglerWatchdog
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.train import TrainState, build_programs
 
 log = logging.getLogger("repro.train")
@@ -64,6 +65,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
 
     cfg = get_config(args.arch)

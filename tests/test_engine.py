@@ -204,6 +204,17 @@ def test_plan_validation():
         SolverEngine(SolverPlan()).topk(_stack(0), 0)
 
 
+def test_float64_pallas_plan_fails_at_planning():
+    """The Pallas kernels take float32 only: a float64 pallas plan is
+    refused when it is built, not deep inside a dispatch."""
+    with pytest.raises(ValueError, match="float64"):
+        SolverPlan(backend="pallas", precision="float64")
+    with pytest.raises(ValueError, match="float64"):
+        plan_for((64, 64), k=4, backend="pallas", precision="float64")
+    assert plan_for((64, 64), k=4, backend="jnp",
+                    precision="float64").precision == "float64"
+
+
 def test_registry_lists_all_backends():
     assert set(available_backends()) >= {"reference", "jnp", "pallas",
                                          "sharded"}

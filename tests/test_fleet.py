@@ -105,6 +105,18 @@ def _wait_for(predicate, timeout_s: float, what: str) -> None:
 # ---------------------------------------------------------------------------
 
 
+def test_subprocess_replica_refuses_a_tpu_parent(monkeypatch):
+    """A parent on the TPU backend holds the chip: starting a worker
+    process that needs it must raise, not run a CPU worker in its place."""
+    import jax
+
+    from repro.engine.fleet import SubprocessReplica
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        SubprocessReplica(0)
+
+
 def test_route_key_deterministic_and_minimal_remap():
     """Rendezvous hashing: deterministic for a (key, candidates, salt)
     triple; removing a non-owner never remaps a key; restoring the dead

@@ -1415,17 +1415,43 @@ def test_property_guard_value_at_edges(n, pad, seed, largest, scale):
 # ---------------------------------------------------------------------------
 
 
+@pytest.fixture()
+def cli_cache_dir():
+    """``serve.py``'s main turns the persistent compile cache on; keep that
+    from outliving the CLI test in this worker process."""
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
 @pytest.mark.parametrize("extra", [[], ["--sync"], ["--linger-ms", "1"],
                                    ["--pack", "always"]],
                          ids=["server", "sync", "linger", "packed"])
-def test_serve_cli_zero_request_stream(extra):
+def test_serve_cli_zero_request_stream(extra, cli_cache_dir):
     from repro.launch import serve as serve_cli
 
     assert serve_cli.main(["--eei", "--requests", "0", "--n", "12",
                            "--k", "2", *extra]) is None
 
 
-def test_serve_cli_packed_stream_smoke():
+def test_serve_cli_exits_nonzero_on_degraded_requests(monkeypatch,
+                                                      cli_cache_dir):
+    """Without --chaos, a request that resolves through the host fallback
+    chain means the device path did not serve it: the CLI exits non-zero
+    instead of logging counters and exiting 0."""
+    from repro.engine import engine as engine_mod
+    from repro.launch import serve as serve_cli
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("device program unavailable")
+
+    monkeypatch.setattr(engine_mod, "topk_program", broken)
+    with pytest.raises(SystemExit) as exc:
+        serve_cli.main(["--eei", "--requests", "2", "--n", "12", "--k", "2"])
+    assert "degraded" in str(exc.value.code)
+
+
+def test_serve_cli_packed_stream_smoke(cli_cache_dir):
     """--eei --pack always on a small mixed stream: the CLI serves it end
     to end and returns the final request's result."""
     from repro.launch import serve as serve_cli

@@ -187,6 +187,29 @@ def test_drift_monitor_forces_full_resolve(rng):
     assert stats["resolves_by_cause"].get("drift") == 4
 
 
+def test_host_reseed_counted_apart_from_device_resolves(rng):
+    """A device re-solve and a host LAPACK reseed both rebuild the window,
+    but only the reseed counts in ``host_reseeds`` — the counter a chip
+    run reads to prove no answer came from the host."""
+    from repro.engine import session as session_mod
+
+    n, k = 12, 2
+    engine = SolverEngine(PLAN)
+    a = _sym(rng, n)
+    session = engine.open_session(
+        a, k, config=SessionConfig(drift_bound=1e-9))
+    u = rng.standard_normal(n)
+    a = a + np.outer(u, u)
+    engine.update(session, Rank1Update(u, 1))
+    assert session.stats()["full_resolves"] == 1
+    assert session.stats()["host_reseeds"] == 0
+    session_mod.host_reseed(session, a)
+    _assert_conformant(a, session.result(), k)
+    stats = session.stats()
+    assert stats["host_reseeds"] == 1
+    assert stats["resolves_by_cause"] == {"drift": 1, "degrade": 1}
+
+
 def test_drift_accumulates_across_small_updates(rng):
     """The bound is on *accumulated* |rho|/||A||_F: many small updates,
     each individually under the bound, must still trip it."""
@@ -341,6 +364,7 @@ def test_server_session_degrades_to_host_solve(rng):
         assert res.fallback == "host_reseed"
         _assert_conformant(a, res, k)
         assert server.stats()["session_degraded"] == 1
+        assert server.session_stats(sid)["host_reseeds"] == 1
 
 
 def test_server_session_malformed_update_fails_future(rng):
