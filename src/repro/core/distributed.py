@@ -104,12 +104,12 @@ def make_sharded_backend(plan: SolverPlan) -> StageLibrary:
         # Batch-parallel like every other stage: each device runs the
         # Lanczos loop on its slice of the stack (k/largest static).
         return shard(lambda x: inner.krylov_reduce(x, k, largest),
-                     (3,), (2, 2, 3))(a)
+                     (3,), (2, 2, 3, 1))(a)
 
     def krylov_shift_invert_reduce(a, k, largest):
         return shard(
             lambda x: inner.krylov_shift_invert_reduce(x, k, largest),
-            (3,), (2, 2, 3, 1))(a)
+            (3,), (2, 2, 3, 1, 1))(a)
 
     return StageLibrary("sharded", {
         "tridiagonalize": tridiagonalize,

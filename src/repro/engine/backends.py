@@ -328,19 +328,22 @@ _REC_TRI_SOLVE = StageSig(
 _REC_DENSE = StageSig(
     "recover", "dense_signs", ("a", "lam_sel", "mag_sel"), ("vecs",))
 # Krylov reduce: a Lanczos band (d (b, m), e (b, m-1)) plus the partial
-# orthonormal basis q (b, n, m).  Every downstream tridiagonal stage is
-# band-size agnostic, so the windowed Sturm / minor-determinant / sign
-# chain runs on the m-band unchanged and the same back-transform through q
-# lifts band eigenvectors to the dense basis (q columns are the basis —
-# exactly Householder's convention with m = n).
-_REDUCE_KRYLOV = StageSig("reduce", "krylov", ("a",), ("d", "e", "q"))
-_REDUCE_KRYLOV_NOQ = StageSig("reduce", "krylov", ("a",), ("d", "e"))
+# orthonormal basis q (b, n, m) and the Lanczos steps taken, steps (b,).
+# Every downstream tridiagonal stage is band-size agnostic, so the windowed
+# Sturm / minor-determinant / sign chain runs on the m-band unchanged and
+# the same back-transform through q lifts band eigenvectors to the dense
+# basis (q columns are the basis — exactly Householder's convention with
+# m = n).
+_REDUCE_KRYLOV = StageSig(
+    "reduce", "krylov", ("a",), ("d", "e", "q", "steps"))
+_REDUCE_KRYLOV_NOQ = StageSig("reduce", "krylov", ("a",), ("d", "e", "steps"))
 # Shift-and-invert: the band lives in theta = 1/(lambda - sigma) space and
 # the recover chain ends with a map stage undoing the transform.
 _REDUCE_SI = StageSig(
-    "reduce", "krylov_shift_invert", ("a",), ("d", "e", "q", "sigma"))
+    "reduce", "krylov_shift_invert", ("a",),
+    ("d", "e", "q", "sigma", "steps"))
 _REDUCE_SI_NOQ = StageSig(
-    "reduce", "krylov_shift_invert", ("a",), ("d", "e", "sigma"))
+    "reduce", "krylov_shift_invert", ("a",), ("d", "e", "sigma", "steps"))
 _SPEC_SI_WIN = StageSig(
     "spectrum", "tridiag_windowed_si", ("d", "e"), ("lam_sel",))
 _MAP_SI = StageSig(

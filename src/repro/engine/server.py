@@ -85,7 +85,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine import engine as engine_mod
-from repro.engine import registry
+from repro.engine import registry, tracing
 from repro.engine.plan import (
     SolverPlan,
     fallback_chain,
@@ -103,6 +103,16 @@ log = logging.getLogger("repro.engine.server")
 #: Default matrix-size granule for shape buckets — the f32 sublane granule
 #: the Pallas block clamp aligns to (``kernels/blocks.clamp_block``).
 N_ALIGN = 8
+
+#: Requests whose latencies ``stats()`` keeps for ``p50/p99_latency_ms``:
+#: the most recent ones, so the record stays bounded for a server's life.
+LATENCY_WINDOW = 4096
+
+#: Host spans of the serving path (``eei.<name>`` in a profiler trace; see
+#: ``engine/tracing.py`` and the "Tracing" section of
+#: ``docs/ARCHITECTURE.md``), each counted as ``<name>_ns`` in ``stats()``.
+SPANS = ("assemble", "copy_in", "launch", "device_wait", "fetch", "retire",
+         "fallback", "session_update")
 
 
 class ServerClosed(RuntimeError):
@@ -283,6 +293,7 @@ class ProgramCache:
         self._programs: "OrderedDict[tuple, object]" = OrderedDict()
         self.hits = 0
         self.misses = 0
+        self.compile_ns = 0  # perf_counter_ns time of the compiles
 
     @property
     def compiles(self) -> int:
@@ -303,9 +314,13 @@ class ProgramCache:
         with self._lock:
             self.hits = 0
             self.misses = 0
+            self.compile_ns = 0
 
     def get(self, bucket, plan: SolverPlan, dtype, *,
             verify: bool = False) -> object:
+        """The compiled program of ``bucket``; compiles it on a miss, in
+        an ``eei.compile`` profiler annotation, timed into
+        ``compile_ns``."""
         key = (bucket, plan, jnp.dtype(dtype).name, bool(verify))
         with self._lock:
             found = self._programs.get(key)
@@ -323,31 +338,38 @@ class ProgramCache:
             if found.error is not None:
                 raise found.error
             return found.program
+        t0 = time.perf_counter_ns()
         try:
-            sds = jax.ShapeDtypeStruct((bucket.b, bucket.n, bucket.n),
-                                       jnp.dtype(dtype))
-            if isinstance(bucket, PackedBucket):
-                fn = engine_mod.packed_topk_program(
-                    plan, bucket.k, bucket.largest, bool(verify))
-                seg_sds = jax.ShapeDtypeStruct(
-                    (bucket.b, bucket.s), jnp.dtype(jnp.int32))
-                prog = fn.lower(sds, seg_sds, seg_sds).compile()
-            else:
-                fn = engine_mod.topk_program(
-                    plan, bucket.k, bucket.largest, bool(verify))
-                prog = fn.lower(sds).compile()
+            with jax.profiler.TraceAnnotation("eei.compile"):
+                prog = self._compile(bucket, plan, dtype, verify)
         except BaseException as exc:
             entry.error = exc
             with self._lock:
+                self.compile_ns += time.perf_counter_ns() - t0
                 if self._programs.get(key) is entry:
                     del self._programs[key]  # next get() retries the compile
             entry.event.set()
             raise
         entry.program = prog
         with self._lock:
+            self.compile_ns += time.perf_counter_ns() - t0
             self._programs[key] = prog
         entry.event.set()
         return prog
+
+    @staticmethod
+    def _compile(bucket, plan: SolverPlan, dtype, verify: bool) -> object:
+        sds = jax.ShapeDtypeStruct((bucket.b, bucket.n, bucket.n),
+                                   jnp.dtype(dtype))
+        if isinstance(bucket, PackedBucket):
+            fn = engine_mod.packed_topk_program(
+                plan, bucket.k, bucket.largest, bool(verify))
+            seg_sds = jax.ShapeDtypeStruct(
+                (bucket.b, bucket.s), jnp.dtype(jnp.int32))
+            return fn.lower(sds, seg_sds, seg_sds).compile()
+        fn = engine_mod.topk_program(
+            plan, bucket.k, bucket.largest, bool(verify))
+        return fn.lower(sds).compile()
 
 
 @dataclasses.dataclass(eq=False)  # identity equality: queue removal by object
@@ -358,6 +380,7 @@ class _Request:
     largest: bool
     future: Future
     t_submit: float
+    stack: Optional[int] = None  # sequence id of the last stack it rode
 
 
 @dataclasses.dataclass
@@ -365,6 +388,7 @@ class _InflightStack:
     result: object  # TopkResult of device arrays, possibly still computing
     requests: list  # the _Requests whose slices ride in this stack
     bucket: object  # ShapeBucket, or PackedBucket for segment-packed stacks
+    seq: int  # dispatch sequence id: the ``stack`` of its spans
     # Packed stacks only: per-request ``(row, slot, offset)`` parallel to
     # ``requests`` — retire slices each request's window out of its slot.
     layout: Optional[list] = None
@@ -589,7 +613,13 @@ class EeiServer:
         self.grid_cells_total = 0
         self.grid_cells_real = 0
         self._pad_cells_by_bucket: dict = {}  # bucket -> [real, total]
-        self.latencies_ms: list = []
+        self.latencies_ms: deque = deque(maxlen=LATENCY_WINDOW)
+        # Span counters (``<span>_ns``), the requests'
+        # summed queue wait (dispatch minus submit, first dispatch only)
+        # and the Lanczos steps of real rows; flat ints in stats().
+        self._spans = tracing.SpanCounters(
+            SPANS, extra=("queue_wait_ns", "lanczos_steps"))
+        self._stack_ids = itertools.count()
         # Robustness counters (see stats()): verification failures routed
         # to the fallback chain, transient retries, bisection splits of
         # failed stacks, requests resolved degraded, and which fallback
@@ -838,37 +868,53 @@ class EeiServer:
             bucket = bucket._replace(b=bucket.b + (-bucket.b) % mult)
         return bucket, plan
 
-    def _launch(self, bucket, plan: SolverPlan, operands: tuple):
+    def _begin_stack(self, requests: list, t_disp: float) -> int:
+        """Number a new stack and mark its riders with it; count the queue
+        wait of riders on their first dispatch (a bisected half or a
+        retried stack carries requests that already waited)."""
+        seq = next(self._stack_ids)
+        wait_s = 0.0
+        for req in requests:
+            if req.stack is None:
+                wait_s += t_disp - req.t_submit
+            req.stack = seq
+        self._spans.add("queue_wait_ns", int(wait_s * 1e9))
+        return seq
+
+    def _launch(self, bucket, plan: SolverPlan, operands: tuple, seq: int):
         """Fetch the bucket program and launch it over ``operands`` (one
         stack for bucketed programs; stack + the two ``(b, s)`` segment
         arrays for packed ones), retrying *transient* failures (see
         :func:`_is_transient`) up to ``max_retries`` with
         decorrelated-jitter backoff.  Chaos compile/launch injection points
         live here — upstream of the retry logic, exactly like the real
-        failures they model."""
+        failures they model.  One ``eei.launch`` span of stack ``seq``
+        covers it all, a compile included."""
         prev_delay = self.retry_backoff_s
-        for attempt in range(self.max_retries + 1):
-            try:
-                if self.chaos is not None:
-                    self.chaos.on_compile()
-                program = self.cache.get(
-                    bucket, plan, self.dtype, verify=self.verify)
-                if self.chaos is not None:
-                    self.chaos.on_launch()
-                return program(*operands)  # async: returns at once
-            except Exception as exc:
-                if attempt >= self.max_retries or not _is_transient(exc):
-                    raise
-                with self._cv:
-                    self.retries += 1
-                    prev_delay = decorrelated_jitter(
-                        self._retry_rng, self.retry_backoff_s, prev_delay,
-                        self.retry_backoff_cap_s)
-                    self.retry_delays_s.append(prev_delay)
-                    self._cv.notify_all()
-                log.warning("EEI dispatch retry %d/%d after transient: %s",
-                            attempt + 1, self.max_retries, exc)
-                time.sleep(prev_delay)  # outside the lock
+        with self._spans.span("launch", stack=seq):
+            for attempt in range(self.max_retries + 1):
+                try:
+                    if self.chaos is not None:
+                        self.chaos.on_compile()
+                    program = self.cache.get(
+                        bucket, plan, self.dtype, verify=self.verify)
+                    if self.chaos is not None:
+                        self.chaos.on_launch()
+                    return program(*operands)  # async: returns at once
+                except Exception as exc:
+                    if attempt >= self.max_retries or not _is_transient(exc):
+                        raise
+                    with self._cv:
+                        self.retries += 1
+                        prev_delay = decorrelated_jitter(
+                            self._retry_rng, self.retry_backoff_s,
+                            prev_delay, self.retry_backoff_cap_s)
+                        self.retry_delays_s.append(prev_delay)
+                        self._cv.notify_all()
+                    log.warning(
+                        "EEI dispatch retry %d/%d after transient: %s",
+                        attempt + 1, self.max_retries, exc)
+                    time.sleep(prev_delay)  # outside the lock
 
     def _dispatch(self, group: list) -> None:
         """Assemble, fetch the program, launch.  Never raises: any failure
@@ -890,15 +936,20 @@ class EeiServer:
         if group and all(self._packable(req) for req in group):
             self._dispatch_packed(group, t_disp)
             return
+        seq = self._begin_stack(group, t_disp)
         try:
             bucket, plan = self._plan_bucket(group)
-            stack = self._assemble(group, bucket)
-            result = self._launch(bucket, plan, (jnp.asarray(stack),))
+            with self._spans.span("assemble", stack=seq):
+                stack = self._assemble(group, bucket)
+            with self._spans.span("copy_in", stack=seq):
+                operands = (jnp.asarray(stack),)
+            result = self._launch(bucket, plan, operands, seq)
         except Exception as exc:  # compile/launch failure after retries:
             self._handle_group_failure(group, exc)  # split / fallback / fail
             return
         with self._cv:
-            self._inflight.append(_InflightStack(result, list(group), bucket))
+            self._inflight.append(
+                _InflightStack(result, list(group), bucket, seq))
             self.stacks_dispatched += 1
             if self.record_dispatches:
                 self.dispatch_log.append(DispatchRecord(
@@ -951,19 +1002,21 @@ class EeiServer:
         for start in range(0, len(rows), self.max_batch):
             chunk = rows[start:start + self.max_batch]
             sub = [group[i] for row in chunk for i, _, _ in row]
+            seq = self._begin_stack(sub, t_disp)
             try:
-                bucket, stack, seg_off, seg_len, layout = \
-                    self._assemble_packed(group, chunk)
-                result = self._launch(
-                    bucket, plan,
-                    (jnp.asarray(stack), jnp.asarray(seg_off),
-                     jnp.asarray(seg_len)))
+                with self._spans.span("assemble", stack=seq):
+                    bucket, stack, seg_off, seg_len, layout = \
+                        self._assemble_packed(group, chunk)
+                with self._spans.span("copy_in", stack=seq):
+                    operands = (jnp.asarray(stack), jnp.asarray(seg_off),
+                                jnp.asarray(seg_len))
+                result = self._launch(bucket, plan, operands, seq)
             except Exception as exc:
                 self._handle_group_failure(sub, exc)
                 continue
             with self._cv:
                 self._inflight.append(_InflightStack(
-                    result, sub, bucket, layout=layout))
+                    result, sub, bucket, seq, layout=layout))
                 self.stacks_dispatched += 1
                 self.packed_stacks_dispatched += 1
                 if self.record_dispatches:
@@ -1085,6 +1138,10 @@ class EeiServer:
         is the pure-numpy eigh oracle.  Resolves the future with a
         :class:`DegradedResult` on the first verified link, or with the
         original cause if every link fails (non-finite input, say)."""
+        with self._spans.span("fallback", stack=req.stack):
+            self._walk_fallback_chain(req, cause)
+
+    def _walk_fallback_chain(self, req: _Request, cause: Exception) -> None:
         a = req.a
         for name, plan in fallback_chain():
             try:
@@ -1145,17 +1202,38 @@ class EeiServer:
         per-request fallback chain instead of resolving with garbage.  A
         device-side failure at the sync point re-enters the split/fallback
         path like a dispatch failure."""
-        result = inflight.result
-        flags_ok = None
+        seq = inflight.seq
+        flags_ok = steps = None
         try:
-            if self.verify:
-                result, flags = result
-                flags_ok = np.asarray(flags.ok)  # sync point
-            lam = np.asarray(result.eigenvalues)  # sync point (verify off)
-            vec = np.asarray(result.vectors)
+            with self._spans.span("device_wait", stack=seq):
+                jax.block_until_ready(inflight.result)
+            with self._spans.span("fetch", stack=seq):
+                result = inflight.result
+                if self.verify:
+                    result, flags = result
+                    flags_ok = np.asarray(flags.ok)
+                    if flags.steps is not None:
+                        steps = np.asarray(flags.steps)
+                lam = np.asarray(result.eigenvalues)
+                vec = np.asarray(result.vectors)
         except Exception as exc:  # device-side failure surfaces here
             self._handle_group_failure(inflight.requests, exc)
             return
+        with self._spans.span("retire", stack=seq):
+            escalate = self._retire_rows(inflight, flags_ok, steps, lam, vec)
+        for req in escalate:
+            cause = VerifyFailed(
+                f"result for (n={req.n}, k={req.k}) failed verification")
+            if self.fallback:
+                self._fallback_request(req, cause)
+            else:
+                self._fail([req], cause)
+
+    def _retire_rows(self, inflight: _InflightStack, flags_ok, steps,
+                     lam: np.ndarray, vec: np.ndarray) -> list:
+        """Slice each request's answer out of a fetched stack, account for
+        the stack and resolve the futures of the rows that passed; returns
+        the requests to escalate."""
         if self.chaos is not None:
             vec = self.chaos.on_result(vec)
             self.chaos.on_retire_sleep()
@@ -1201,6 +1279,11 @@ class EeiServer:
                     escalate.append(req)
                     continue
                 results.append((req, engine_mod.TopkResult(lam_r, vec_r)))
+        if steps is not None:
+            # Rows past the requests are batch padding: their steps are
+            # device work no request asked for.
+            self._spans.add("lanczos_steps",
+                            int(np.sum(steps[:len(inflight.requests)])))
         # Counters update BEFORE futures resolve: a caller woken by
         # future.result() may read stats() immediately and must see this
         # stack's requests already accounted for.
@@ -1215,13 +1298,7 @@ class EeiServer:
             self._cv.notify_all()
         for req, res in results:
             self._set(req.future, result=res)
-        for req in escalate:
-            cause = VerifyFailed(
-                f"result for (n={req.n}, k={req.k}) failed verification")
-            if self.fallback:
-                self._fallback_request(req, cause)
-            else:
-                self._fail([req], cause)
+        return escalate
 
     def _account_retired_locked(self, inflight: _InflightStack) -> None:
         """Pad-waste cell accounting, exactly once per *successfully
@@ -1585,6 +1662,11 @@ class EeiServer:
         else (a broken fast path, a sick backend) degrades to a host
         full solve from the mirror, so the session survives every fault
         the PR-7 chain survives."""
+        with self._spans.span("session_update", session=rec.sid):
+            self._session_apply_update(rec, u, sign, fut)
+
+    def _session_apply_update(self, rec: _ServerSession, u: np.ndarray,
+                              sign: int, fut: Future) -> None:
         from repro.engine import session as session_mod
 
         u64 = np.asarray(u, dtype=np.float64)
@@ -1875,7 +1957,7 @@ class EeiServer:
             self.grid_cells_total = 0
             self.grid_cells_real = 0
             self._pad_cells_by_bucket = {}
-            self.latencies_ms = []
+            self.latencies_ms = deque(maxlen=LATENCY_WINDOW)
             self.dispatch_log = []
             self.verify_failed = 0
             self.retries = 0
@@ -1891,6 +1973,7 @@ class EeiServer:
             self.session_fast_updates = 0
             self.session_full_resolves = 0
             self.session_degraded = 0
+        self._spans.reset()
         self.cache.reset_counters()
 
     def stats(self) -> dict:
@@ -1913,7 +1996,16 @@ class EeiServer:
         makes: more guard cells per launch in exchange for far fewer
         launches and compiled programs on fragmented ragged traffic.
         Compare each fraction against its own history, not against the
-        other path's."""
+        other path's.
+
+        ``p50_latency_ms`` / ``p99_latency_ms`` are submit-to-resolve
+        percentiles over the last ``LATENCY_WINDOW`` requests.  Every span
+        of ``SPANS`` adds ``<span>_ns``, and ``program_compile_ns`` is the
+        cache's compile time (inside ``launch_ns``);
+        ``queue_wait_ns`` sums each request's wait from submit to its first
+        dispatch, and ``lanczos_steps`` the Lanczos steps of every real row
+        of a Krylov stack.  All are flat ints, zeroed by ``reset_stats()``.
+        """
         with self._cv:
             lat = sorted(self.latencies_ms)
             packed_real = packed_total = buck_real = buck_total = 0
@@ -1974,9 +2066,11 @@ class EeiServer:
 
         snap.update({
             "program_compiles": self.cache.compiles,
+            "program_compile_ns": self.cache.compile_ns,
             "program_hits": self.cache.hits,
             "distinct_buckets": len(self.cache),
             "p50_latency_ms": pct(50),
             "p99_latency_ms": pct(99),
+            **self._spans.snapshot(),
         })
         return snap

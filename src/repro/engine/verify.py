@@ -36,7 +36,7 @@ as NaN, which the finiteness check catches outright.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -60,6 +60,8 @@ class VerifyFlags(NamedTuple):
     All fields carry the stack's leading batch axis ``(b,)``.  ``ok`` is
     the conjunction of the individual checks; ``residual`` is the worst
     relative residual (units of ``||A||_F``) for observability / debugging.
+    ``steps`` is filled by the serving program when its chain has a Krylov
+    reduce: the Lanczos steps each row took (``None`` otherwise).
     """
 
     ok: jax.Array          # (b,) bool — all checks passed
@@ -68,6 +70,7 @@ class VerifyFlags(NamedTuple):
     norm_ok: jax.Array     # (b,) bool — rows unit-norm within norm_tol
     ordered: jax.Array     # (b,) bool — selected eigenvalues ascend
     residual: jax.Array    # (b,) float — worst relative residual
+    steps: Optional[jax.Array] = None  # (b,) float32 — Lanczos steps
 
 
 def _spectral_scale(a: jnp.ndarray) -> jnp.ndarray:
@@ -239,5 +242,5 @@ def verify_topk_host(a: np.ndarray, lam_sel: np.ndarray, vecs: np.ndarray,
     flags = VerifyFlags(ok=ok, finite=finite, residual_ok=residual_ok,
                         norm_ok=norm_ok, ordered=ordered, residual=worst)
     if squeeze:
-        flags = VerifyFlags(*(f[0] for f in flags))
+        flags = VerifyFlags(*(f if f is None else f[0] for f in flags))
     return flags

@@ -75,7 +75,7 @@ def serve_eei(args):
     """
     from repro.engine import EeiServer, SolverEngine, autotune, plan_for, \
         resolved_crossovers
-    from repro.engine.server import make_eei_stream
+    from repro.engine.server import SPANS, make_eei_stream
 
     if args.calibration:
         autotune.set_table(autotune.load_table(args.calibration))
@@ -184,6 +184,19 @@ def serve_eei(args):
              stats["p50_latency_ms"], stats["p99_latency_ms"],
              stats["stacks_dispatched"], stats["program_compiles"],
              stats["distinct_buckets"], stats["program_hits"])
+    # The server's span counters: host phases and device wait per stack
+    # (the compile is part of launch), queue wait and Lanczos steps per
+    # request.
+    stacks = max(stats["stacks_dispatched"], 1)
+    served = max(stats["requests_completed"], 1)
+    phases = ", ".join(
+        f"{name}={stats[key] / stacks / 1e6:.3f}" for name, key in
+        [(name, f"{name}_ns") for name in SPANS if name != "session_update"]
+        + [("compile", "program_compile_ns")])
+    log.info("per stack (ms): %s | per request: queue wait %.3f ms, "
+             "%.1f Lanczos steps", phases,
+             stats["queue_wait_ns"] / served / 1e6,
+             stats["lanczos_steps"] / served)
     per_bucket = ", ".join(
         f"{name}={frac:.3f}"
         for name, frac in sorted(stats["pad_waste_by_bucket"].items()))

@@ -271,11 +271,12 @@ def lanczos_partial(
 
 def krylov_reduce(a: jax.Array, k: int, largest: bool = True, m: int = 0,
                   rtol: float = 0.0):
-    """Single-matrix krylov reduce stage: ``(d, e, q)`` for a top-k window."""
+    """Single-matrix krylov reduce stage: ``(d, e, q, steps)`` for a top-k
+    window; ``steps`` is the number of Lanczos steps the loop took."""
     n = a.shape[-1]
     mm = _resolve_m(n, k, m)
     res = lanczos_partial(a, mm, min(k, mm), largest, rtol=rtol)
-    return res.d, res.e, res.q
+    return res.d, res.e, res.q, res.steps
 
 
 @functools.partial(jax.jit, static_argnames=("k", "largest", "m", "rtol"))
@@ -302,7 +303,8 @@ def shift_invert_sigma(a: jax.Array, largest: bool = True):
 
 def krylov_shift_invert_reduce(a: jax.Array, k: int, largest: bool = True,
                                m: int = 0, rtol: float = 0.0):
-    """Shift-and-invert krylov reduce: ``(d, e, q, sigma)`` in theta-space.
+    """Shift-and-invert krylov reduce: ``(d, e, q, sigma, steps)`` in
+    theta-space.
 
     Lanczos runs on ``B = (A - sigma I)^{-1}`` through one LU
     factorization; the band's Ritz values are ``theta = 1/(lambda - sigma)``
@@ -317,7 +319,7 @@ def krylov_shift_invert_reduce(a: jax.Array, k: int, largest: bool = True,
     mv = lambda v: jax.scipy.linalg.lu_solve((lu, piv), v)
     res = lanczos_partial(a, mm, min(k, mm), not largest, matvec=mv,
                           rtol=rtol)
-    return res.d, res.e, res.q, sigma
+    return res.d, res.e, res.q, sigma, res.steps
 
 
 @functools.partial(jax.jit, static_argnames=("k", "largest", "m", "rtol"))

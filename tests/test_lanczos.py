@@ -176,8 +176,9 @@ def test_default_band_sizes():
     assert default_si_m(4096, 16) == 128
     # krylov_reduce honors an explicit m override (band shape = m).
     a = jnp.asarray(_matrix("goe", 32, seed=0))
-    d, e, q = krylov_reduce(a, 2, True, m=8)
+    d, e, q, steps = krylov_reduce(a, 2, True, m=8)
     assert d.shape == (8,) and e.shape == (7,) and q.shape == (32, 8)
+    assert steps.shape == () and 1 <= int(steps) <= 8
 
 
 # ---------------------------------------------------------------------------
