@@ -21,9 +21,16 @@ Robustness follows the classical playbook:
   rank-deficient matrices in ``tests/test_lanczos.py``).
 * **Residual-based stopping**: every ``check_every`` steps the windowed
   Ritz values of the current band are bisected and the Ritz residual bound
-  ``|A y - theta y| = beta_j |s_j[last]|`` evaluated; the loop exits when
+  ``|A y - theta y| = beta_j |s_j[last]|`` evaluated; a matrix stops when
   every windowed pair meets ``rtol`` (relative to the band's spectral
-  scale) — or at the ``m`` cap.
+  scale) — or at the ``m`` cap.  The loop runs natively on a stack
+  ``(b, n, n)`` with its control shared by the stack: one unbatched step
+  counter, so the check is a real ``cond`` that runs only at its check
+  steps, and a per-matrix stop flag that freezes a converged member's
+  carry until every member has stopped.  The loop is not ``vmap``-ped:
+  vmap batches a while loop's predicate when the stop flag differs per
+  matrix, which turns every ``cond`` in the body into a ``select`` that
+  runs the check (a bisection and two recurrences) at every step.
 * **Breakdown restart**: ``beta_j ~ 0`` means an exact invariant subspace
   was captured.  The iteration restarts with a fresh pseudo-random
   direction orthogonalized against the basis; the band decouples through an
@@ -47,8 +54,8 @@ the recover chain maps Ritz values back with ``lambda = sigma + 1/theta``
 
 from __future__ import annotations
 
-import functools
-from typing import NamedTuple, Optional, Tuple
+import math
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -95,7 +102,9 @@ def _default_rtol(dtype) -> float:
 
 
 class LanczosResult(NamedTuple):
-    """One partial tridiagonalization, guard-masked and engine-oriented."""
+    """One partial tridiagonalization, guard-masked and engine-oriented.
+
+    Shapes are per matrix; a stack's leading axes come first."""
 
     d: jax.Array  # (m,) band diagonal; guard value beyond `steps`
     e: jax.Array  # (m-1,) band off-diagonal; 0 beyond the active block
@@ -140,26 +149,37 @@ def _mask_band(d, e, j, m, largest: bool):
 def lanczos_iterate(
     a: jax.Array,
     m: int,
+    window: Tuple[int, bool],
     *,
-    window: Optional[Tuple[int, bool]] = None,
     matvec=None,
     rtol: float = 0.0,
     check_every: int = 32,
     seed: int = 0,
 ):
-    """Raw m-step Lanczos loop on one matrix (or abstract ``matvec``).
+    """Raw m-step Lanczos loop on a stack ``a (b, n, n)`` (or an abstract
+    ``matvec`` mapping the stack's vectors ``(b, n)`` to ``(b, n)``).
 
-    Returns ``(d (m,), e (m,), Q (m+1, n) rows, steps, resid)`` — the
-    unmasked internals; :func:`lanczos_partial` is the masked public form.
-    ``window=(k, largest)`` enables the windowed Ritz residual stop.
+    Returns ``(d (b, m), e (b, m), Q (b, m+1, n) rows, steps (b,),
+    resid (b, k))`` — the unmasked internals; :func:`lanczos_partial` is
+    the masked public form.  ``window=(k, largest)`` drives the windowed
+    Ritz residual stop.
+
+    The loop's control is shared by the stack (see the module docstring for
+    why it is not vmapped): one unbatched step counter ``j``, so the Ritz
+    check's ``cond`` stays a real conditional.  A member that has met
+    ``rtol`` keeps its carry frozen and its ``steps`` at the step it
+    stopped; the loop ends when every member has stopped or at the ``m``
+    cap.
     """
-    n = a.shape[-1]
+    b, n = a.shape[0], a.shape[-1]
     dtype = a.dtype
-    mv = matvec if matvec is not None else (lambda v: a @ v)
+    mv = matvec if matvec is not None else (
+        lambda v: jax.vmap(jnp.matmul)(a, v))
+    k_win, largest = window
     if not 1 <= m <= n:
         raise ValueError(f"Krylov band m={m} out of range for n={n}")
-    if window is not None and not 1 <= window[0] <= m:
-        raise ValueError(f"window k={window[0]} out of range for m={m}")
+    if not 1 <= k_win <= m:
+        raise ValueError(f"window k={k_win} out of range for m={m}")
     rtol = float(rtol) if rtol else _default_rtol(dtype)
     eps = jnp.asarray(jnp.finfo(dtype).eps, dtype)
     floor = jnp.asarray(jnp.finfo(dtype).tiny, dtype) ** 0.5
@@ -167,73 +187,85 @@ def lanczos_iterate(
     key = jax.random.PRNGKey(seed)
     v0 = jax.random.normal(key, (n,), dtype)
     v0 = v0 / jnp.linalg.norm(v0)
-    k_win = window[0] if window is not None else 1
 
     def ritz_resid(d, e, j1, beta):
-        """Relative Ritz residual bound for the k windowed pairs of the
-        current masked band: ``beta_j |s_i[j-1]| / scale``."""
-        k, largest = window
-        d_m, e_m = _mask_band(d, e, j1, m, largest)
-        theta = sturm.bisect_eigenvalues_windowed(d_m, e_m, k, largest)
-        mags = identity.tridiag_windowed_magnitudes(d_m, e_m, theta)
-        s_last = jnp.sqrt(jnp.maximum(mags[:, j1 - 1], 0.0))
-        lo, hi = _band_bounds(d_m, e_m, jnp.arange(m) < j1)
+        """Relative Ritz residual bound for the k windowed pairs of each
+        member's current masked band: ``beta_j |s_i[j-1]| / scale``."""
+        d_m, e_m = jax.vmap(
+            lambda dd, ee: _mask_band(dd, ee, j1, m, largest))(d, e)
+        theta = sturm.bisect_eigenvalues_windowed_batched(
+            d_m, e_m, k_win, largest)
+        mags = identity.tridiag_windowed_magnitudes_batched(d_m, e_m, theta)
+        s_last = jnp.sqrt(jnp.maximum(mags[..., j1 - 1], 0.0))
+        lo, hi = jax.vmap(
+            lambda dd, ee: _band_bounds(dd, ee, jnp.arange(m) < j1))(
+                d_m, e_m)
         scale = jnp.maximum(jnp.maximum(jnp.abs(lo), jnp.abs(hi)), floor)
-        return beta * s_last / scale
+        return beta[:, None] * s_last / scale[:, None]
 
-    def body(carry):
-        Q, d, e, j, resid, done = carry
-        qj = Q[j]
-        w = mv(qj)
+    def orthogonalize(Q, qj, w):
+        """One member's three-term step and full reorthogonalization."""
         alpha = jnp.dot(qj, w)
         w = w - alpha * qj
         # Full reorthogonalization, CGS2: rows of Q beyond the basis are
         # exactly zero, so no masking is needed in the projections.
         w = w - Q.T @ (Q @ w)
         w = w - Q.T @ (Q @ w)
-        beta = jnp.linalg.norm(w)
-        d = d.at[j].set(alpha)
-        scale = jnp.maximum(jnp.max(jnp.abs(d)), jnp.max(jnp.abs(e)))
+        return alpha, w, jnp.linalg.norm(w)
+
+    def body(carry):
+        Q, d, e, j, steps, resid, done = carry
+        live = ~done
+        qj = Q[:, j]
+        alpha, w, beta = jax.vmap(orthogonalize)(Q, qj, mv(qj))
+        d_j = jnp.where(live, alpha, d[:, j])
+        d = d.at[:, j].set(d_j)
+        scale = jnp.maximum(jnp.max(jnp.abs(d), axis=-1),
+                            jnp.max(jnp.abs(e), axis=-1))
         breakdown = beta <= jnp.maximum(100.0 * eps * scale, floor)
 
-        def restart(_):
+        def restart(qn):
             # Invariant subspace captured: continue in a fresh direction
             # orthogonal to the basis (one projection pass suffices for a
             # random vector), through an exactly-zero band junction.
             r = jax.random.normal(jax.random.fold_in(key, j + 1), (n,), dtype)
-            r = r - Q.T @ (Q @ r)
-            rn = jnp.linalg.norm(r)
-            return jnp.where(rn > floor, r / jnp.maximum(rn, floor), 0.0)
+            r = jnp.broadcast_to(r, (b, n))  # projected member by member
+            r = r - jax.vmap(lambda Qb, rb: Qb.T @ (Qb @ rb))(Q, r)
+            rn = jnp.linalg.norm(r, axis=-1, keepdims=True)
+            fresh = jnp.where(rn > floor, r / jnp.maximum(rn, floor), 0.0)
+            return jnp.where(breakdown[:, None], fresh, qn)
 
         qn = jax.lax.cond(
-            breakdown, restart,
-            lambda _: w / jnp.maximum(beta, floor), None)
-        e = e.at[j].set(jnp.where(breakdown, 0.0, beta))
-        Q = Q.at[j + 1].set(qn)
+            jnp.any(breakdown & live), restart, lambda qn: qn,
+            w / jnp.maximum(beta, floor)[:, None])
+        e_j = jnp.where(live, jnp.where(breakdown, 0.0, beta), e[:, j])
+        e = e.at[:, j].set(e_j)
+        Q = Q.at[:, j + 1].set(jnp.where(live[:, None], qn, Q[:, j + 1]))
         j1 = j + 1
-        if window is not None:
-            do_check = (j1 % check_every == 0) & (j1 >= k_win + 1)
-            resid = jax.lax.cond(
-                do_check,
-                lambda _: ritz_resid(d, e, j1, beta),
-                lambda _: resid, None)
-            done = jnp.all(resid <= rtol)
-        return Q, d, e, j1, resid, done
+        do_check = (j1 % check_every == 0) & (j1 >= k_win + 1)
+        resid = jax.lax.cond(
+            do_check,
+            lambda r: jnp.where(live[:, None], ritz_resid(d, e, j1, beta), r),
+            lambda r: r, resid)
+        steps = jnp.where(live, j1, steps)
+        done = done | jnp.all(resid <= rtol, axis=-1)
+        return Q, d, e, j1, steps, resid, done
 
     def cond(carry):
-        _, _, _, j, _, done = carry
-        return (j < m) & (~done)
+        j, done = carry[3], carry[-1]
+        return (j < m) & ~jnp.all(done)
 
     carry0 = (
-        jnp.zeros((m + 1, n), dtype).at[0].set(v0),
-        jnp.zeros((m,), dtype),
-        jnp.zeros((m,), dtype),
+        jnp.zeros((b, m + 1, n), dtype).at[:, 0].set(v0),
+        jnp.zeros((b, m), dtype),
+        jnp.zeros((b, m), dtype),
         jnp.asarray(0, jnp.int32),
-        jnp.full((k_win,), jnp.inf, dtype),
-        jnp.asarray(False),
+        jnp.zeros((b,), jnp.int32),
+        jnp.full((b, k_win), jnp.inf, dtype),
+        jnp.zeros((b,), bool),
     )
-    Q, d, e, j, resid, _ = jax.lax.while_loop(cond, body, carry0)
-    return d, e, Q, j, resid
+    Q, d, e, _, steps, resid, _ = jax.lax.while_loop(cond, body, carry0)
+    return d, e, Q, steps, resid
 
 
 def lanczos_partial(
@@ -249,19 +281,26 @@ def lanczos_partial(
 ) -> LanczosResult:
     """Guard-masked m-step Lanczos band + basis for a ``(k, largest)`` window.
 
-    ``d (m,)`` / ``e (m-1,)`` carry the active block with inactive slots
-    guard-filled away from the window; ``q (n, m)`` columns are the basis
-    (zero beyond ``steps``).  The triple plugs directly into the windowed
-    spectrum/components/recover stages.
+    ``a`` is one matrix ``(n, n)`` or a stack ``(..., n, n)``; the leading
+    axes are flattened into the one batch axis of :func:`lanczos_iterate`
+    (a single matrix is the ``b = 1`` stack), and ``matvec``, if given,
+    maps that flattened stack's vectors ``(b, n)`` to ``(b, n)``.  Per
+    matrix, ``d (m,)`` / ``e (m-1,)`` carry the active block with inactive
+    slots guard-filled away from the window; ``q (n, m)`` columns are the
+    basis (zero beyond ``steps``).  The triple plugs directly into the
+    windowed spectrum/components/recover stages.
     """
-    d, e, Q, j, resid = lanczos_iterate(
-        a, m, window=(k, largest), matvec=matvec, rtol=rtol,
-        check_every=check_every, seed=seed)
-    d_m, e_m = _mask_band(d, e, j, m, largest)
+    lead, n = a.shape[:-2], a.shape[-1]
+    d, e, Q, steps, resid = lanczos_iterate(
+        a.reshape((math.prod(lead), n, n)), m, (k, largest), matvec=matvec,
+        rtol=rtol, check_every=check_every, seed=seed)
+    d_m, e_m = jax.vmap(
+        lambda dd, ee, s: _mask_band(dd, ee, s, m, largest))(d, e, steps)
     # Row `steps` of Q was written by the last body step but is outside the
     # retained basis — zero everything beyond the active block.
-    q = jnp.where(jnp.arange(m)[:, None] < j, Q[:m], 0.0)
-    return LanczosResult(d_m, e_m, jnp.swapaxes(q, -1, -2), j, resid)
+    q = jnp.where(jnp.arange(m)[:, None] < steps[:, None, None], Q[:, :m], 0.0)
+    res = LanczosResult(d_m, e_m, jnp.swapaxes(q, -1, -2), steps, resid)
+    return LanczosResult(*(x.reshape(lead + x.shape[1:]) for x in res))
 
 
 # ---------------------------------------------------------------------------
@@ -271,22 +310,18 @@ def lanczos_partial(
 
 def krylov_reduce(a: jax.Array, k: int, largest: bool = True, m: int = 0,
                   rtol: float = 0.0):
-    """Single-matrix krylov reduce stage: ``(d, e, q, steps)`` for a top-k
-    window; ``steps`` is the number of Lanczos steps the loop took."""
+    """Krylov reduce stage: ``(d, e, q, steps)`` for a top-k window of a
+    matrix ``(n, n)`` or a stack ``(..., n, n)``; ``steps`` is the number
+    of Lanczos steps each matrix took."""
     n = a.shape[-1]
     mm = _resolve_m(n, k, m)
     res = lanczos_partial(a, mm, min(k, mm), largest, rtol=rtol)
     return res.d, res.e, res.q, res.steps
 
 
-@functools.partial(jax.jit, static_argnames=("k", "largest", "m", "rtol"))
-def krylov_reduce_batched(a: jax.Array, k: int, largest: bool = True,
-                          m: int = 0, rtol: float = 0.0):
-    """Leading-axis batched :func:`krylov_reduce`."""
-    from repro.linalg.batching import vmap_leading
-
-    fn = lambda aa: krylov_reduce(aa, k, largest, m, rtol)
-    return vmap_leading(fn, a.ndim - 2)(a)
+#: Jitted :func:`krylov_reduce` — the engine's entry for a stack.
+krylov_reduce_batched = jax.jit(
+    krylov_reduce, static_argnames=("k", "largest", "m", "rtol"))
 
 
 def shift_invert_sigma(a: jax.Array, largest: bool = True):
@@ -304,30 +339,28 @@ def shift_invert_sigma(a: jax.Array, largest: bool = True):
 def krylov_shift_invert_reduce(a: jax.Array, k: int, largest: bool = True,
                                m: int = 0, rtol: float = 0.0):
     """Shift-and-invert krylov reduce: ``(d, e, q, sigma, steps)`` in
-    theta-space.
+    theta-space, for a matrix ``(n, n)`` or a stack ``(..., n, n)``.
 
     Lanczos runs on ``B = (A - sigma I)^{-1}`` through one LU
-    factorization; the band's Ritz values are ``theta = 1/(lambda - sigma)``
-    and the *opposite* extreme of theta corresponds to the requested extreme
-    of lambda (the ``shift_invert_map`` recover stage undoes both).
+    factorization per matrix; the band's Ritz values are
+    ``theta = 1/(lambda - sigma)`` and the *opposite* extreme of theta
+    corresponds to the requested extreme of lambda (the
+    ``shift_invert_map`` recover stage undoes both).
     """
-    n = a.shape[-1]
+    lead, n = a.shape[:-2], a.shape[-1]
     mm = _resolve_m(n, k, m, si=True)
-    sigma = shift_invert_sigma(a, largest)
+    a3 = a.reshape((math.prod(lead), n, n))
+    sigma = jax.vmap(lambda x: shift_invert_sigma(x, largest))(a3)
     lu, piv = jax.scipy.linalg.lu_factor(
-        a - sigma * jnp.eye(n, dtype=a.dtype))
-    mv = lambda v: jax.scipy.linalg.lu_solve((lu, piv), v)
-    res = lanczos_partial(a, mm, min(k, mm), not largest, matvec=mv,
-                          rtol=rtol)
-    return res.d, res.e, res.q, sigma, res.steps
+        a3 - sigma[:, None, None] * jnp.eye(n, dtype=a.dtype))
+    solve = jax.vmap(
+        lambda lu_b, piv_b, v: jax.scipy.linalg.lu_solve((lu_b, piv_b), v))
+    res = lanczos_partial(a, mm, min(k, mm), not largest,
+                          matvec=lambda v: solve(lu, piv, v), rtol=rtol)
+    return res.d, res.e, res.q, sigma.reshape(lead), res.steps
 
 
-@functools.partial(jax.jit, static_argnames=("k", "largest", "m", "rtol"))
-def krylov_shift_invert_reduce_batched(a: jax.Array, k: int,
-                                       largest: bool = True, m: int = 0,
-                                       rtol: float = 0.0):
-    """Leading-axis batched :func:`krylov_shift_invert_reduce`."""
-    from repro.linalg.batching import vmap_leading
-
-    fn = lambda aa: krylov_shift_invert_reduce(aa, k, largest, m, rtol)
-    return vmap_leading(fn, a.ndim - 2)(a)
+#: Jitted :func:`krylov_shift_invert_reduce` — the engine's entry for a
+#: stack.
+krylov_shift_invert_reduce_batched = jax.jit(
+    krylov_shift_invert_reduce, static_argnames=("k", "largest", "m", "rtol"))

@@ -13,6 +13,9 @@ The robustness contract of the krylov reduce stage (see
 * breakdown (an exact invariant subspace) restarts in a fresh orthogonal
   direction through an exactly-zero band junction, so rank-deficient
   matrices still fill a k-window wider than their rank;
+* a stack runs one loop whose control it shares: each member stops on its
+  own and matches the single-matrix reduce row by row, and the Ritz check
+  runs only at its check steps;
 * the ``eei_krylov`` / ``eei_krylov_si`` compositions run the *existing*
   windowed chain on the band and match the ``eigh`` oracle through every
   backend library.
@@ -40,10 +43,14 @@ from repro.linalg import (
     default_m,
     default_si_m,
     krylov_reduce,
+    krylov_reduce_batched,
+    krylov_shift_invert_reduce,
+    krylov_shift_invert_reduce_batched,
     lanczos_partial,
     ritz_interlacing_holds,
     shift_invert_sigma,
 )
+from repro.linalg import sturm
 
 BACKENDS = ["reference", "jnp", "pallas"]
 
@@ -179,6 +186,109 @@ def test_default_band_sizes():
     d, e, q, steps = krylov_reduce(a, 2, True, m=8)
     assert d.shape == (8,) and e.shape == (7,) and q.shape == (32, 8)
     assert steps.shape == () and 1 <= int(steps) <= 8
+
+
+# ---------------------------------------------------------------------------
+# One loop for a stack: shared control, per-member stopping
+# ---------------------------------------------------------------------------
+
+
+def _mixed_stack(lead: tuple, early: str = "spiked",
+                 n: int = 64) -> np.ndarray:
+    """float32 stack alternating a matrix that stops at the first Ritz
+    check and a Wishart matrix (no gap at the spectrum's edge, so it runs
+    to the ``m`` cap).  The early one is a spiked Wishart matrix, or with
+    ``early="invariant"`` a diagonal of four distinct values: an exact
+    invariant subspace after four steps, so the loop restarts (the values
+    are exact in float32, so the breakdown is clean)."""
+    rng = np.random.default_rng(17)
+    mats = []
+    for i in range(int(np.prod(lead))):
+        x = rng.standard_normal((n, 2 * n))
+        if i % 2 == 0 and early == "invariant":
+            mats.append(np.diag(np.repeat([1.0, 2.0, 3.0, 4.0], n // 4)))
+            continue
+        if i % 2 == 0:
+            x[:4] *= 3.0
+        mats.append(x @ x.T / (2 * n))
+    return np.stack(mats).reshape(lead + (n, n)).astype(np.float32)
+
+
+_REDUCES = {
+    "direct": (krylov_reduce_batched, krylov_reduce),
+    "shift_invert": (krylov_shift_invert_reduce_batched,
+                     krylov_shift_invert_reduce),
+}
+
+
+@pytest.mark.parametrize("mode,early,lead", [
+    ("direct", "spiked", (2,)),
+    ("direct", "spiked", (2, 2)),
+    ("direct", "invariant", (2,)),
+    ("shift_invert", "spiked", (2,)),
+    ("shift_invert", "spiked", (2, 2)),
+], ids=["direct-b2", "direct-b2x2", "direct-restart-b2",
+        "shift_invert-b2", "shift_invert-b2x2"])
+def test_stack_reduce_matches_per_matrix_reduce(mode, early, lead):
+    """A stack where one member stops at the first check and the other runs
+    to the cap gives, row by row, the single-matrix reduce's steps and
+    (within float32 rounding) its band, basis and shift."""
+    a = _mixed_stack(lead, early)
+    batched, single = _REDUCES[mode]
+    k, m = 4, 64
+    out = batched(jnp.asarray(a), k, True, m)
+    steps = np.asarray(out[-1])
+    assert steps.shape == lead
+    assert steps.min() < m and steps.max() == m  # members stop apart
+    for idx in np.ndindex(*lead):
+        ref = single(jnp.asarray(a[idx]), k, True, m)
+        assert int(ref[-1]) == int(steps[idx])
+        for got, want in zip(out[:-1], ref[:-1]):
+            np.testing.assert_allclose(
+                np.asarray(got[idx]), np.asarray(want),
+                rtol=1e-4, atol=1e-4 * float(np.max(np.abs(want))))
+
+
+def test_stack_lanczos_partial_matches_per_matrix_resid():
+    """The Ritz residuals that decide each member's stop are the
+    single-matrix loop's, member by member."""
+    a = _mixed_stack((2,))
+    res = lanczos_partial(jnp.asarray(a), 64, 4)
+    assert int(res.steps[0]) < 64 == int(res.steps[1])
+    for i in range(2):
+        ref = lanczos_partial(jnp.asarray(a[i]), 64, 4)
+        assert int(ref.steps) == int(res.steps[i])
+        # A converged pair's residual is rounding; agree to the stop
+        # rule's resolution (float32 rtol 1e-5).
+        np.testing.assert_allclose(np.asarray(res.resid[i]),
+                                   np.asarray(ref.resid), rtol=1e-3,
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(res.d[i]), np.asarray(ref.d),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_stack_ritz_check_runs_only_at_its_check_steps(monkeypatch):
+    """The Ritz check's bisection runs once per check step for the whole
+    stack, not at every Lanczos step: a loop vmapped over the stack turns
+    the check's ``cond`` into a ``select`` that runs it every step."""
+    calls = []
+    bisect = sturm.bisect_eigenvalues_windowed_batched
+
+    def counted(d, e, k, largest=True, n_iter=0):
+        jax.debug.callback(lambda: calls.append(1))
+        return bisect(d, e, k, largest, n_iter)
+
+    monkeypatch.setattr(sturm, "bisect_eigenvalues_windowed_batched", counted)
+    rng = np.random.default_rng(23)
+    a = rng.standard_normal((2, 64, 64)).astype(np.float32)
+    a = jnp.asarray(a + np.swapaxes(a, 1, 2))
+    # rtol no residual meets: both members run to m = 64, steps 1..64 hold
+    # two check steps (32 and 64).
+    krylov_reduce_batched.clear_cache()  # trace again, with the counter
+    d, e, q, steps = krylov_reduce_batched(a, 4, True, 64, rtol=1e-30)
+    jax.effects_barrier()
+    assert np.asarray(steps).tolist() == [64, 64]
+    assert len(calls) == 2
 
 
 # ---------------------------------------------------------------------------
