@@ -179,6 +179,18 @@ def _eigh_oracle(a: np.ndarray, k: int, largest: bool):
     return lam[:k], v[:, :k].T
 
 
+def _device_snapshot(a: np.ndarray) -> jax.Array:
+    """``a`` on the device as a copy that later host writes to ``a`` cannot
+    reach.  Where the device shares the host's memory (the CPU backend) the
+    transfer maps an aligned buffer instead of copying it; a mapped result
+    is remade from a private host copy.  The wait ends the transfer before
+    the caller's buffer is let go."""
+    dev = jnp.asarray(a)
+    if dev.unsafe_buffer_pointer() == a.ctypes.data:
+        dev = jnp.asarray(a.copy())
+    return dev.block_until_ready()
+
+
 def _bucket_n(n: int, align: int) -> int:
     """Matrix-size bucket: ``n`` rounded up to the block-grid granule."""
     return -(-n // align) * align
@@ -403,7 +415,11 @@ class DispatchRecord:
 
     bucket: object  # ShapeBucket, or PackedBucket for packed dispatches
     plan: SolverPlan
-    stack: np.ndarray  # the assembled (bucket.b, bucket.n, bucket.n) input
+    #: The (bucket.b, bucket.n, bucket.n) input.  Where assembly is the
+    #: identity (one request of the bucket's own n in a b = 1 bucket) it is
+    #: a view of the request's matrix, which the caller may since have
+    #: written; the device got a snapshot taken at dispatch.
+    stack: np.ndarray
     requests: list  # [_Request, ...] in row (packed: layout) order
     #: ``time.monotonic()`` when the group left the admission queue —
     #: before assembly/compile, so ``t_dispatch - request.t_submit`` is the
@@ -600,6 +616,7 @@ class EeiServer:
         self.requests_rejected = 0  # late submits after close()
         self.requests_cancelled = 0  # caller-cancelled while still pending
         self.stacks_dispatched = 0
+        self.stacks_unassembled = 0  # sent as the request's own matrix
         self.packed_stacks_dispatched = 0
         self.packed_requests_completed = 0
         # Pad-waste accounting: every grid cell of a stack (b * n^2) versus
@@ -927,6 +944,11 @@ class EeiServer:
         packability is re-derived here (not trusted from the coalesce key)
         so bisection halves of a failed packed stack re-pack consistently.
 
+        A lone request whose matrix already has the bucket's shape (b = 1,
+        ``n`` aligned) skips assembly: its own matrix goes to the device as
+        a ``(1, n, n)`` view, copied there by :func:`_device_snapshot`, and
+        ``stacks_unassembled`` counts the stack.
+
         Pad-waste cell accounting deliberately does NOT happen here: cells
         are counted once per *successfully retired* stack (see
         ``_account_retired_locked``), because counting at launch double- or
@@ -940,9 +962,16 @@ class EeiServer:
         try:
             bucket, plan = self._plan_bucket(group)
             with self._spans.span("assemble", stack=seq):
-                stack = self._assemble(group, bucket)
+                req = group[0]
+                unassembled = (len(group) == 1 and bucket.b == 1
+                               and req.n == bucket.n
+                               and req.a.dtype == self.dtype)
+                stack = (req.a[None] if unassembled
+                         else self._assemble(group, bucket))
             with self._spans.span("copy_in", stack=seq):
-                operands = (jnp.asarray(stack),)
+                # The view is the caller's buffer: copy it for certain.
+                operands = (_device_snapshot(stack) if unassembled
+                            else jnp.asarray(stack),)
             result = self._launch(bucket, plan, operands, seq)
         except Exception as exc:  # compile/launch failure after retries:
             self._handle_group_failure(group, exc)  # split / fallback / fail
@@ -951,6 +980,7 @@ class EeiServer:
             self._inflight.append(
                 _InflightStack(result, list(group), bucket, seq))
             self.stacks_dispatched += 1
+            self.stacks_unassembled += unassembled
             if self.record_dispatches:
                 self.dispatch_log.append(DispatchRecord(
                     bucket=bucket, plan=plan, stack=stack,
@@ -1952,6 +1982,7 @@ class EeiServer:
             self.requests_rejected = 0
             self.requests_cancelled = 0
             self.stacks_dispatched = 0
+            self.stacks_unassembled = 0
             self.packed_stacks_dispatched = 0
             self.packed_requests_completed = 0
             self.grid_cells_total = 0
@@ -1987,7 +2018,9 @@ class EeiServer:
         fallback chain contribute nothing.  ``stacks_dispatched`` counts
         *launches* instead, so ``stacks_dispatched`` can exceed the number
         of retired stacks under chaos while the cell counters match the
-        equivalent clean run.  ``pad_waste_bucketed_frac`` /
+        equivalent clean run; ``stacks_unassembled`` counts the launches
+        whose one request went to the device as it is, with no host
+        stack.  ``pad_waste_bucketed_frac`` /
         ``pad_waste_packed_frac`` split the waste by dispatch path.  The
         packed fraction counts every cell of the ``(b, n, n)`` packed
         rows, which charges a block-diagonal row quadratically for its
@@ -2025,6 +2058,7 @@ class EeiServer:
                 "requests_pending": self._pending,
                 "requests_unresolved": len(self._unresolved),
                 "stacks_dispatched": self.stacks_dispatched,
+                "stacks_unassembled": self.stacks_unassembled,
                 "packed_stacks_dispatched": self.packed_stacks_dispatched,
                 "packed_requests_completed": self.packed_requests_completed,
                 "grid_cells_total": self.grid_cells_total,

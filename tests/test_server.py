@@ -38,7 +38,12 @@ from repro.engine import (
     SolverPlan,
     verify_topk_host,
 )
-from repro.engine.server import PackedBucket, _bucket_n, make_eei_stream
+from repro.engine.server import (
+    PackedBucket,
+    _bucket_n,
+    _device_snapshot,
+    make_eei_stream,
+)
 from repro.kernels import blocks
 from repro.runtime import ChaosConfig, ChaosMonkey
 
@@ -294,6 +299,106 @@ def test_bucket_rounds_up_to_mesh_batch_axis(monkeypatch):
         np.testing.assert_allclose(
             lam, np.asarray(engine.topk(jnp.asarray(a), k).eigenvalues),
             rtol=1e-5, atol=1e-5)
+
+
+def _aligned_sym(rng, n: int, align: int = 64) -> np.ndarray:
+    """A symmetric matrix whose buffer starts on an ``align``-byte
+    boundary: the CPU backend's transfer maps such a buffer instead of
+    copying it, so a write to it would reach an uncopied device array."""
+    buf = np.empty(n * n + align // 4, np.float32)
+    off = (-buf.ctypes.data % align) // 4
+    a = buf[off: off + n * n].reshape(n, n)
+    a[...] = _sym(rng, n)
+    return a
+
+
+@pytest.mark.parametrize("case", ["lone_aligned", "unaligned_n",
+                                  "group_of_2", "mesh_rounded"])
+def test_dispatch_assembles_only_when_needed(case, monkeypatch):
+    """A lone request of the bucket's own n in a b = 1 bucket goes to the
+    device as a view of its own matrix; guard rows, a second request or a
+    bucket rounded up to the mesh batch axis still assemble a host stack.
+    Either way the answer is bitwise the program's on the explicitly
+    assembled stack."""
+    if case == "mesh_rounded":
+        monkeypatch.setattr(SolverPlan, "batch_axis_size",
+                            property(lambda self: 2))
+    rng = np.random.default_rng(21)
+    n = 13 if case == "unaligned_n" else 16
+    mats = [_aligned_sym(rng, n)
+            for _ in range(2 if case == "group_of_2" else 1)]
+    server = EeiServer(PLAN, max_batch=8, record_dispatches=True)
+    futs = [server.submit(a, 3) for a in mats]
+    server.flush()
+    (rec,) = server.dispatch_log
+    stats = server.stats()
+    unassembled = case == "lone_aligned"
+    assert stats["stacks_dispatched"] == 1
+    assert stats["stacks_unassembled"] == int(unassembled)
+    assert np.shares_memory(rec.stack, mats[0]) == unassembled
+    assert rec.bucket.b == {"group_of_2": 2, "mesh_rounded": 2}.get(case, 1)
+    explicit = server._assemble(rec.requests, rec.bucket)
+    np.testing.assert_array_equal(rec.stack, explicit)
+    ref = SolverEngine(rec.plan).topk(
+        jnp.asarray(explicit), rec.bucket.k, rec.bucket.largest)
+    lam, vec = np.asarray(ref.eigenvalues), np.asarray(ref.vectors)
+    for row, fut in enumerate(futs):
+        res = fut.result()
+        assert not res.degraded
+        np.testing.assert_array_equal(res.eigenvalues, lam[row, -3:])
+        np.testing.assert_array_equal(res.vectors, vec[row, -3:, :n])
+    server.reset_stats()
+    assert server.stats()["stacks_unassembled"] == 0
+
+
+@pytest.mark.parametrize("offset", [0, 16], ids=["aligned", "offset16"])
+def test_device_snapshot_is_not_reached_by_later_writes(offset):
+    """The CPU backend maps a 64-byte-aligned host buffer and copies any
+    other one after ``jnp.asarray`` returns (seen from 2048 x 2048 float32
+    up): either way a write right after the call would reach the device
+    array.  The snapshot is made before it returns."""
+    n = 2048
+    buf = np.empty(n * n + 32, np.float32)
+    start = ((-buf.ctypes.data % 64) + offset) // 4
+    a = buf[start: start + n * n].reshape(n, n)
+    a[...] = 1.0
+    dev = _device_snapshot(a[None])
+    a[...] = 0.0
+    assert bool(jnp.all(dev == 1.0))
+
+
+def test_write_after_dispatch_does_not_reach_the_answer(monkeypatch):
+    """Caller-driven: the client overwrites its matrix after the stack went
+    out and before it retired.  The program ran on the bytes of dispatch
+    time, and the answer is the clean matrix's, bit for bit."""
+    rng = np.random.default_rng(22)
+    a = _aligned_sym(rng, 16)
+    clean = a.copy()
+    server = EeiServer(PLAN, max_batch=1, max_inflight=2)
+    operands = []
+    get = server.cache.get
+
+    def spying_get(*args, **kwargs):
+        program = get(*args, **kwargs)
+
+        def run(*ops):
+            operands.extend(ops)
+            return program(*ops)
+        return run
+
+    monkeypatch.setattr(server.cache, "get", spying_get)
+    fut = server.submit(a, 3)
+    assert server.stats()["stacks_unassembled"] == 1 and not fut.done()
+    a[...] = 0.0
+    server.flush()
+    np.testing.assert_array_equal(np.asarray(operands[0])[0], clean)
+    ref = SolverEngine(PLAN).topk(jnp.asarray(clean[None]), 4)
+    res = fut.result()
+    assert not res.degraded
+    np.testing.assert_array_equal(res.eigenvalues,
+                                  np.asarray(ref.eigenvalues)[0, -3:])
+    np.testing.assert_array_equal(res.vectors,
+                                  np.asarray(ref.vectors)[0, -3:])
 
 
 def test_non_pow2_max_batch_floors_to_bound():
